@@ -228,23 +228,26 @@ def _apply_reference(x: jax.Array, layer: SALRLinear,
     the quantized-repr oracle dequantizes ``layer.qbase`` here."""
     if base is None:
         base = layer.base
-    w = materialize_base(base)
-    if _is_tiled(base) or isinstance(base, QDenseWeight):
-        w = w[:, :layer.d_out]            # drop tile/block zero-padding
-    if w.dtype != x.dtype:
-        w = w.astype(x.dtype)
-    if constrain_fn is not None and not _is_tiled(base) \
-            and not isinstance(base, QDenseWeight):
-        # the storage-rows sharding convention only applies to flat bases
-        w = constrain_fn(w)
-    if layer.transposed:
-        y = jax.lax.dot_general(
-            x, w, (((x.ndim - 1,), (1,)), ((), ())), precision=precision)
-    else:
-        y = jax.lax.dot_general(
-            x, w, (((x.ndim - 1,), (0,)), ((), ())), precision=precision)
-    a_cat, b_cat = adapter_cat(layer)
-    y = y + (x @ a_cat) @ b_cat
+    with jax.named_scope("salr_base_decode"):
+        w = materialize_base(base)
+        if _is_tiled(base) or isinstance(base, QDenseWeight):
+            w = w[:, :layer.d_out]        # drop tile/block zero-padding
+        if w.dtype != x.dtype:
+            w = w.astype(x.dtype)
+        if constrain_fn is not None and not _is_tiled(base) \
+                and not isinstance(base, QDenseWeight):
+            # the storage-rows sharding convention only applies to flat bases
+            w = constrain_fn(w)
+    with jax.named_scope("salr_base"):
+        if layer.transposed:
+            y = jax.lax.dot_general(
+                x, w, (((x.ndim - 1,), (1,)), ((), ())), precision=precision)
+        else:
+            y = jax.lax.dot_general(
+                x, w, (((x.ndim - 1,), (0,)), ((), ())), precision=precision)
+    with jax.named_scope("salr_adapter"):
+        a_cat, b_cat = adapter_cat(layer)
+        y = y + (x @ a_cat) @ b_cat
     if layer.bias is not None:
         y = y + layer.bias
     return y

@@ -33,9 +33,16 @@ one decode step for the whole slot batch.  Accounting covers TTFT,
 tok/s, queue depth, slot occupancy, admission wait, and per-bucket
 prefill counts on a virtual clock fed by the measured wall time of the
 jitted calls (idle gaps fast-forward to the next arrival instead of
-sleeping).  ``reset()`` clears ALL scheduling state and every metric
-accumulator -- warm reruns start from a clean clock while keeping the
-compiled callables and cache buffers.
+sleeping).  Under ``jax.profiler.trace`` each tick also leaves spans on
+the profiler's clock, nested as the calls nest: ``engine.step`` holds
+``engine.admit`` (``rid=`` the request's id; children
+``engine.page_plan``, ``engine.prefill``, ``engine.admit_wait``) and
+``engine.decode`` (``engine.decode_dispatch``, ``engine.decode_wait``,
+``engine.emit``), and every page-table upload is an
+``engine.page_table_upload``.  ``h2d_uploads`` counts the host arrays
+the engine sends to the device.  ``reset()`` clears ALL scheduling
+state and every metric accumulator -- warm reruns start from a clean
+clock while keeping the compiled callables and cache buffers.
 
 KV storage is PAGED when the resolved plan's decode route says so
 (``plan.route("decode").kv == "paged"``, the resolver default — the
@@ -82,6 +89,7 @@ reported truthfully: ``metrics()["moe_route_prefill"]`` /
 from __future__ import annotations
 
 import bisect
+import contextlib
 import dataclasses
 import time
 from typing import Callable, Optional, Sequence
@@ -430,6 +438,7 @@ class ContinuousBatchingEngine:
         self._bucket_counts: dict = {}        # prefill bucket -> count
         self.n_prefills = 0
         self.n_decode_ticks = 0
+        self.h2d_uploads = 0
         # paged-KV state: fresh pool/radix (deterministic allocation
         # order), all page-table rows to the null page
         self.pool = PagePool(self.n_pages) if self.paged else None
@@ -441,6 +450,27 @@ class ContinuousBatchingEngine:
         if self.paged:
             self._page_table = np.zeros((n, self.max_pages), np.int32)
             self.cache["page_table"] = jnp.asarray(self._page_table)
+
+    # ---------------------------------------------------- spans and uploads
+
+    @contextlib.contextmanager
+    def _span(self, name: str, clock: bool = False, **meta):
+        """``name`` as a profiler span; a ``clock`` span (device-bound
+        work) also advances the virtual clock by its ``time_fn`` time."""
+        with jax.profiler.TraceAnnotation(name, **meta):
+            t0 = self._time() if clock else 0.0
+            yield
+            if clock:
+                self.now += self._time() - t0
+
+    def _to_device(self, x, dtype=None) -> jax.Array:
+        """One host -> device array, counted in ``h2d_uploads``."""
+        self.h2d_uploads += 1
+        return jnp.asarray(x, dtype)
+
+    def _upload_page_table(self) -> None:
+        with jax.profiler.TraceAnnotation("engine.page_table_upload"):
+            self.cache["page_table"] = self._to_device(self._page_table)
 
     # ------------------------------------------------------------- intake
 
@@ -533,49 +563,53 @@ class ContinuousBatchingEngine:
         length = len(req.prompt)
         hit: list = []
         lp = 0
-        if self.paged:
-            # step() already verified feasibility via _pages_available;
-            # the re-match returns the same pages (nothing mutated since)
-            hit, n_new, bucket, lp = self._page_plan(req)
-            new_pages = self.pool.alloc(n_new)
-            assert new_pages is not None, "admission without free pages"
-            self.pool.incref(hit)         # this request's ref on shared pages
-            pages = hit + new_pages
-            row = np.zeros((self.max_pages,), np.int32)
-            row[:len(pages)] = pages
-            self._page_table[slot] = row
-            self.cache["page_table"] = jnp.asarray(self._page_table)
-            self._pages_per_req.append(len(pages))
-            self._shared_prompt_tokens += lp
-            self._total_prompt_tokens += length
-        else:
-            pages = None
-            bucket = pick_bucket(length, self.buckets)
+        with self._span("engine.page_plan"):
+            if self.paged:
+                # step() already verified feasibility via _pages_available;
+                # the re-match returns the same pages (nothing mutated since)
+                hit, n_new, bucket, lp = self._page_plan(req)
+                new_pages = self.pool.alloc(n_new)
+                assert new_pages is not None, "admission without free pages"
+                self.pool.incref(hit)     # this request's ref on shared pages
+                pages = hit + new_pages
+                row = np.zeros((self.max_pages,), np.int32)
+                row[:len(pages)] = pages
+                self._page_table[slot] = row
+                self._upload_page_table()
+                self._pages_per_req.append(len(pages))
+                self._shared_prompt_tokens += lp
+                self._total_prompt_tokens += length
+            else:
+                pages = None
+                bucket = pick_bucket(length, self.buckets)
         suffix = req.prompt[lp:]
         padded = np.full((1, bucket), self.ecfg.pad_id, np.int32)
         padded[0, :len(suffix)] = np.asarray(suffix, np.int32)
         fe = (None if req.frontend is None
-              else jnp.asarray(req.frontend)[None])
+              else self._to_device(req.frontend)[None])
         # queue wait is time spent pending, not the request's own prefill
         self._admit_waits.append(max(0.0, self.now - req.arrival))
-        t0 = self._time()
-        if lp:
-            # continuation prefill: gather the shared pages into a dense
-            # batch=1 prefix, prefill only the suffix at offset lp
-            prefix_cache = self._gather(self.cache,
-                                        jnp.asarray(hit, jnp.int32))
-            tok0, rcache = self._prefill(self.params, jnp.asarray(padded),
-                                         jnp.int32(len(suffix) - 1),
-                                         fe, prefix_cache, lp)
-        else:
-            tok0, rcache = self._prefill(self.params, jnp.asarray(padded),
-                                         jnp.int32(self.prefix + length - 1),
-                                         fe, None, 0)
-        self.cache = self._insert(self.cache, rcache, jnp.int32(slot),
-                                  jnp.int32(lp))
-        tok0 = int(tok0[0])
-        jax.block_until_ready(jax.tree_util.tree_leaves(self.cache)[0])
-        self.now += self._time() - t0
+        with self._span("engine.prefill", clock=True):
+            if lp:
+                # continuation prefill: gather the shared pages into a
+                # dense batch=1 prefix, prefill only the suffix at offset lp
+                prefix_cache = self._gather(self.cache,
+                                            self._to_device(hit, jnp.int32))
+                tok0, rcache = self._prefill(
+                    self.params, self._to_device(padded),
+                    self._to_device(len(suffix) - 1, jnp.int32), fe,
+                    prefix_cache, lp)
+            else:
+                tok0, rcache = self._prefill(
+                    self.params, self._to_device(padded),
+                    self._to_device(self.prefix + length - 1, jnp.int32), fe,
+                    None, 0)
+            self.cache = self._insert(self.cache, rcache,
+                                      self._to_device(slot, jnp.int32),
+                                      self._to_device(lp, jnp.int32))
+        with self._span("engine.admit_wait", clock=True):
+            tok0 = int(tok0[0])
+            jax.block_until_ready(jax.tree_util.tree_leaves(self.cache)[0])
         self.n_prefills += 1
         self._bucket_counts[bucket] = self._bucket_counts.get(bucket, 0) + 1
         if self.sharable:
@@ -605,47 +639,52 @@ class ContinuousBatchingEngine:
             # decode writes can never corrupt a reallocated page.
             self.pool.decref(act.pages)
             self._page_table[act.slot] = 0
-            self.cache["page_table"] = jnp.asarray(self._page_table)
+            self._upload_page_table()
 
     def _decode_tick(self) -> None:
-        tokens = jnp.asarray(self._last_tok[:, None])
-        pos = jnp.asarray(self._pos)
-        t0 = self._time()
-        nxt, self.cache = self._decode(self.params, self.cache, tokens, pos)
-        nxt = np.asarray(nxt)                 # blocks on the step
-        self.now += self._time() - t0
+        with self._span("engine.decode_dispatch", clock=True):
+            tokens = self._to_device(self._last_tok[:, None])
+            pos = self._to_device(self._pos)
+            nxt, self.cache = self._decode(self.params, self.cache, tokens,
+                                           pos)
+        with self._span("engine.decode_wait", clock=True):
+            nxt = np.asarray(nxt)                 # blocks on the step
         self.n_decode_ticks += 1
         self._occupancy.append(self.n_active)
-        for slot, act in enumerate(self.slots):
-            if act is None:
-                continue
-            act.result.tokens.append(int(nxt[slot]))
-            self._last_tok[slot] = nxt[slot]
-            self._pos[slot] += 1
-            if len(act.result.tokens) >= act.req.max_new_tokens:
-                self._finish(act)
+        with self._span("engine.emit"):
+            for slot, act in enumerate(self.slots):
+                if act is None:
+                    continue
+                act.result.tokens.append(int(nxt[slot]))
+                self._last_tok[slot] = nxt[slot]
+                self._pos[slot] += 1
+                if len(act.result.tokens) >= act.req.max_new_tokens:
+                    self._finish(act)
 
     def step(self) -> bool:
         """One scheduler tick: admit arrived requests into free slots,
         then advance every active slot by one token.  Returns False when
         fully drained (nothing active, nothing pending)."""
-        self._queue_depths.append(len(self.pending))
-        admitted = 0
-        while (self.pending and self.slots.count(None)
-               and self.pending[0][0] <= self.now
-               and admitted < self.ecfg.max_prefills_per_tick):
-            if not self._pages_available(self.pending[0][2]):
-                break                 # head-of-line blocks on page pressure
-            _, _, req = self.pending.pop(0)
-            self._admit(req, self.free_slots()[0])
-            admitted += 1
-        if self.n_active:
-            self._decode_tick()
-            return True
-        if self.pending:                      # idle: jump to next arrival
-            self.now = max(self.now, self.pending[0][0])
-            return True
-        return False
+        with self._span("engine.step"):
+            self._queue_depths.append(len(self.pending))
+            admitted = 0
+            while (self.pending and self.slots.count(None)
+                   and self.pending[0][0] <= self.now
+                   and admitted < self.ecfg.max_prefills_per_tick):
+                if not self._pages_available(self.pending[0][2]):
+                    break             # head-of-line blocks on page pressure
+                _, _, req = self.pending.pop(0)
+                with self._span("engine.admit", rid=req.rid):
+                    self._admit(req, self.free_slots()[0])
+                admitted += 1
+            if self.n_active:
+                with self._span("engine.decode"):
+                    self._decode_tick()
+                return True
+            if self.pending:                  # idle: jump to next arrival
+                self.now = max(self.now, self.pending[0][0])
+                return True
+            return False
 
     def run(self, requests: Optional[Sequence[Request]] = None):
         """Drain the queue; returns ({rid: RequestResult}, metrics)."""
@@ -679,6 +718,7 @@ class ContinuousBatchingEngine:
             "prefills_per_bucket": dict(sorted(self._bucket_counts.items())),
             "n_prefills": self.n_prefills,
             "n_decode_ticks": self.n_decode_ticks,
+            "h2d_uploads": self.h2d_uploads,
             "n_slots": self.ecfg.n_slots,
             "buckets": self.buckets,
             "kv_layout": "paged" if self.paged else "dense",

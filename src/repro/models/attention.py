@@ -425,41 +425,47 @@ def apply_gqa(p, x: jax.Array, cfg: ArchConfig, *, local: bool = False,
             new_cache = RingKVCache(k=kc, v=vc, ring_pos=ring)
             y = decode_attention(q, kc, vc, valid)
         elif isinstance(cache, PagedQuantKVCache):
-            ps = cache.k.shape[1]
-            pages = page_table[rows, pv // ps]
-            off = pv % ps
-            kq, ks = _q8(k)
-            vq, vs = _q8(v)
-            kc = cache.k.at[pages, off].set(kq[:, 0])
-            vc = cache.v.at[pages, off].set(vq[:, 0])
-            ksc = cache.k_scale.at[pages, off].set(ks[:, 0])
-            vsc = cache.v_scale.at[pages, off].set(vs[:, 0])
+            with jax.named_scope("kv_write"):
+                ps = cache.k.shape[1]
+                pages = page_table[rows, pv // ps]
+                off = pv % ps
+                kq, ks = _q8(k)
+                vq, vs = _q8(v)
+                kc = cache.k.at[pages, off].set(kq[:, 0])
+                vc = cache.v.at[pages, off].set(vq[:, 0])
+                ksc = cache.k_scale.at[pages, off].set(ks[:, 0])
+                vsc = cache.v_scale.at[pages, off].set(vs[:, 0])
             new_cache = PagedQuantKVCache(k=kc, v=vc, k_scale=ksc,
                                           v_scale=vsc)
-            y = paged_quant_gqa_attention(q, kc, vc, ksc, vsc,
-                                          page_table, pv)
+            with jax.named_scope("paged_gqa"):
+                y = paged_quant_gqa_attention(q, kc, vc, ksc, vsc,
+                                              page_table, pv)
         elif isinstance(cache, PagedNF4KVCache):
-            ps = cache.k.shape[1]
-            pages = page_table[rows, pv // ps]
-            off = pv % ps
-            kq, ks = _qnf4(k)
-            vq, vs = _qnf4(v)
-            kc = cache.k.at[pages, off].set(kq[:, 0])
-            vc = cache.v.at[pages, off].set(vq[:, 0])
-            ksc = cache.k_scale.at[pages, off].set(ks[:, 0])
-            vsc = cache.v_scale.at[pages, off].set(vs[:, 0])
+            with jax.named_scope("kv_write"):
+                ps = cache.k.shape[1]
+                pages = page_table[rows, pv // ps]
+                off = pv % ps
+                kq, ks = _qnf4(k)
+                vq, vs = _qnf4(v)
+                kc = cache.k.at[pages, off].set(kq[:, 0])
+                vc = cache.v.at[pages, off].set(vq[:, 0])
+                ksc = cache.k_scale.at[pages, off].set(ks[:, 0])
+                vsc = cache.v_scale.at[pages, off].set(vs[:, 0])
             new_cache = PagedNF4KVCache(k=kc, v=vc, k_scale=ksc,
                                         v_scale=vsc)
-            y = paged_nf4_gqa_attention(q, kc, vc, ksc, vsc,
-                                        page_table, pv)
+            with jax.named_scope("paged_gqa"):
+                y = paged_nf4_gqa_attention(q, kc, vc, ksc, vsc,
+                                            page_table, pv)
         elif isinstance(cache, PagedKVCache):
-            ps = cache.k.shape[1]
-            pages = page_table[rows, pv // ps]
-            off = pv % ps
-            kc = cache.k.at[pages, off].set(k[:, 0])
-            vc = cache.v.at[pages, off].set(v[:, 0])
+            with jax.named_scope("kv_write"):
+                ps = cache.k.shape[1]
+                pages = page_table[rows, pv // ps]
+                off = pv % ps
+                kc = cache.k.at[pages, off].set(k[:, 0])
+                vc = cache.v.at[pages, off].set(v[:, 0])
             new_cache = PagedKVCache(k=kc, v=vc)
-            y = paged_gqa_attention(q, kc, vc, page_table, pv)
+            with jax.named_scope("paged_gqa"):
+                y = paged_gqa_attention(q, kc, vc, page_table, pv)
         elif isinstance(cache, QuantKVCache):
             kq, ks = _q8(k)
             vq, vs = _q8(v)

@@ -39,6 +39,9 @@ MIXER_APPLY = {
     "slstm": xl.apply_slstm,
 }
 
+# mixers whose named scope is ``attn``; a recurrent mixer's scope is its kind
+ATTN_KINDS = ("attn", "attn_local", "mla")
+
 
 def _group_mlp(cfg: ArchConfig, group: LayerGroup) -> str:
     return group.mlp if group.mlp is not None else cfg.mlp
@@ -150,26 +153,29 @@ def apply_layer(p, x, cfg: ArchConfig, kind: str, mlp_kind: str, *,
     continuation) reach only the self-attention mixer; cross-attention
     K/V stay slot-dense and are never prefix-shared."""
     mixer_cache = cache.get("mixer") if cache else None
-    x, new_mixer = MIXER_APPLY[kind](
-        p["mixer"], x, cfg, positions=positions, mode=mode,
-        cache=mixer_cache, pos=pos, causal=causal, last_pos=last_pos,
-        route=route, page_table=page_table,
-        prefix=prefix_cache.get("mixer") if prefix_cache else None,
-        q_offset=q_offset)
-    new_cache = {"mixer": new_mixer}
-    if "cross" in p:
-        cross_cache = cache.get("cross") if cache else None
-        x, new_cross = attn.apply_gqa(
-            p["cross"], x, cfg, local=False, positions=positions, mode=mode,
-            cache=cross_cache, pos=pos, memory=memory, causal=False,
-            route=route)
-        new_cache["cross"] = new_cross
+    with jax.named_scope("attn" if kind in ATTN_KINDS else kind):
+        x, new_mixer = MIXER_APPLY[kind](
+            p["mixer"], x, cfg, positions=positions, mode=mode,
+            cache=mixer_cache, pos=pos, causal=causal, last_pos=last_pos,
+            route=route, page_table=page_table,
+            prefix=prefix_cache.get("mixer") if prefix_cache else None,
+            q_offset=q_offset)
+        new_cache = {"mixer": new_mixer}
+        if "cross" in p:
+            cross_cache = cache.get("cross") if cache else None
+            x, new_cross = attn.apply_gqa(
+                p["cross"], x, cfg, local=False, positions=positions,
+                mode=mode, cache=cross_cache, pos=pos, memory=memory,
+                causal=False, route=route)
+            new_cache["cross"] = new_cross
     if mlp_kind == "moe":
-        x = moe_mod.apply_moe(p["moe"], x, cfg, route=route)
+        with jax.named_scope("moe"):
+            x = moe_mod.apply_moe(p["moe"], x, cfg, route=route)
     elif mlp_kind != "none":
-        x = x + apply_mlp(p["mlp"], apply_rmsnorm(p["mlp_norm"], x,
-                                                  cfg.norm_eps), mlp_kind,
-                          route=route)
+        with jax.named_scope("mlp"):
+            x = x + apply_mlp(p["mlp"], apply_rmsnorm(p["mlp_norm"], x,
+                                                      cfg.norm_eps),
+                              mlp_kind, route=route)
     return x, new_cache
 
 
@@ -245,15 +251,19 @@ def forward_hidden(params, cfg: ArchConfig, tokens: jax.Array,
     memory = None
     if cfg.family == "encdec":
         memory = _encode(params, cfg, frontend_embeds, route)
-    x = _embed_inputs(params, cfg, tokens, frontend_embeds)
-    x = constrain_activation(x)
+    with jax.named_scope("embed"):
+        x = _embed_inputs(params, cfg, tokens, frontend_embeds)
+        x = constrain_activation(x)
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
-    for gi, g in enumerate(cfg.layer_groups):
-        x, _ = apply_group(params["groups"][gi], x, cfg, g, mode="train",
-                           positions=positions, memory=memory, route=route)
-        x = constrain_activation(x)
-    return apply_rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    with jax.named_scope("layers"):
+        for gi, g in enumerate(cfg.layer_groups):
+            x, _ = apply_group(params["groups"][gi], x, cfg, g, mode="train",
+                               positions=positions, memory=memory,
+                               route=route)
+            x = constrain_activation(x)
+    with jax.named_scope("head"):
+        return apply_rmsnorm(params["final_norm"], x, cfg.norm_eps)
 
 
 def forward_train(params, cfg: ArchConfig, tokens: jax.Array,
@@ -349,7 +359,8 @@ def prefill(params, cfg: ArchConfig, tokens: jax.Array,
     memory = None
     if cfg.family == "encdec":
         memory = _encode(params, cfg, frontend_embeds, route)
-    x = _embed_inputs(params, cfg, tokens, frontend_embeds)
+    with jax.named_scope("embed"):
+        x = _embed_inputs(params, cfg, tokens, frontend_embeds)
     b, s, _ = x.shape
     positions = jnp.broadcast_to(
         pos_offset + jnp.arange(s, dtype=jnp.int32), (b, s))
@@ -358,20 +369,23 @@ def prefill(params, cfg: ArchConfig, tokens: jax.Array,
         last_pos = jnp.broadcast_to(jnp.asarray(logit_index, jnp.int32),
                                     (b,))
     caches = []
-    for gi, g in enumerate(cfg.layer_groups):
-        pcs = prefix_cache["groups"][gi] if prefix_cache else None
-        x, nc = apply_group(params["groups"][gi], x, cfg, g, mode="prefill",
-                            positions=positions, memory=memory,
-                            last_pos=last_pos, route=route,
-                            prefix_caches=pcs, q_offset=pos_offset)
-        caches.append(nc)
-    x = apply_rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    if logit_index is None:
-        x_last = x[:, -1:]
-    else:
-        idx = jnp.broadcast_to(jnp.asarray(logit_index, jnp.int32), (b,))
-        x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)
-    logits = apply_lm_head(params["lm_head"], x_last)
+    with jax.named_scope("layers"):
+        for gi, g in enumerate(cfg.layer_groups):
+            pcs = prefix_cache["groups"][gi] if prefix_cache else None
+            x, nc = apply_group(params["groups"][gi], x, cfg, g,
+                                mode="prefill", positions=positions,
+                                memory=memory, last_pos=last_pos,
+                                route=route, prefix_caches=pcs,
+                                q_offset=pos_offset)
+            caches.append(nc)
+    with jax.named_scope("head"):
+        x = apply_rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        if logit_index is None:
+            x_last = x[:, -1:]
+        else:
+            idx = jnp.broadcast_to(jnp.asarray(logit_index, jnp.int32), (b,))
+            x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)
+        logits = apply_lm_head(params["lm_head"], x_last)
     cache = {"groups": caches}
     if memory is not None:
         cache["memory"] = memory
@@ -397,19 +411,23 @@ def decode_step(params, cfg: ArchConfig, cache, tokens: jax.Array,
         pos = jnp.pad(pos, (0, pad))
         cache = dict(cache, page_table=jnp.pad(cache["page_table"],
                                                ((0, pad), (0, 0))))
-    x = apply_embedding(params["embed"], tokens)
+    with jax.named_scope("embed"):
+        x = apply_embedding(params["embed"], tokens)
     memory = cache.get("memory")
     page_table = cache.get("page_table")
     positions = pos[:, None]
     new_groups = []
-    for gi, g in enumerate(cfg.layer_groups):
-        x, nc = apply_group(params["groups"][gi], x, cfg, g, mode="decode",
-                            positions=positions, caches=cache["groups"][gi],
-                            pos=pos, memory=memory, route=route,
-                            page_table=page_table)
-        new_groups.append(nc)
-    x = apply_rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = apply_lm_head(params["lm_head"], x)[:b]
+    with jax.named_scope("layers"):
+        for gi, g in enumerate(cfg.layer_groups):
+            x, nc = apply_group(params["groups"][gi], x, cfg, g,
+                                mode="decode", positions=positions,
+                                caches=cache["groups"][gi], pos=pos,
+                                memory=memory, route=route,
+                                page_table=page_table)
+            new_groups.append(nc)
+    with jax.named_scope("head"):
+        x = apply_rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        logits = apply_lm_head(params["lm_head"], x)[:b]
     new_cache = {"groups": new_groups}
     if memory is not None:
         new_cache["memory"] = memory

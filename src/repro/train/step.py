@@ -39,8 +39,9 @@ def make_loss_fn(cfg: ArchConfig, loss_chunk: int = 512,
         x = M.forward_hidden(params, cfg, batch["tokens"],
                              batch.get("frontend"), plan=plan)
         # frontend prefix positions carry no labels
-        return M.lm_loss_chunked(params["lm_head"], x, batch["labels"],
-                                 prefix_len=prefix, chunk=loss_chunk)
+        with jax.named_scope("head"):
+            return M.lm_loss_chunked(params["lm_head"], x, batch["labels"],
+                                     prefix_len=prefix, chunk=loss_chunk)
 
     return loss_fn
 

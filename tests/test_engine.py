@@ -1,6 +1,8 @@
 """Continuous-batching engine: scheduler unit tests (admission order,
 slot reuse after eviction, bucket selection) and end-to-end exact token
 parity with ``greedy_generate``."""
+import collections
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -353,3 +355,130 @@ def test_int8_paged_engine_parity():
         ref = greedy_generate(params, cfg, jnp.asarray(r.prompt)[None],
                               n_steps=3, ctx=32, plan=eng.plan)
         assert results[r.rid].tokens == list(np.asarray(ref[0])), r.rid
+
+
+# ------------------------------------------- spans, scopes and uploads
+
+def _host_spans(trace_dir):
+    """[(name, start, end, stats)] of the profiler trace's host events on
+    the thread that ran the ``caller`` span."""
+    import glob
+    import os
+    path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    for plane in data.planes:
+        for line in plane.lines:
+            evs = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                    dict(ev.stats)) for ev in line.events]
+            if any(e[0] == "caller" for e in evs):
+                return evs
+    raise AssertionError("no caller span in the trace")
+
+
+def _inside(child, parent):
+    return parent[1] <= child[1] and child[2] <= parent[2]
+
+
+def test_engine_spans_nest_under_the_callers_span(tmp_path):
+    """Under jax.profiler.trace each tick is an engine.step span inside
+    the caller's own span, holding engine.admit (with the request id)
+    and engine.decode, each holding its named children."""
+    cfg, params = _cfg_params()
+    eng = ContinuousBatchingEngine(
+        cfg, params, EngineConfig(n_slots=2, max_ctx=16,
+                                  backend="reference"), time_fn=_FROZEN)
+    reqs = [Request(rid=10 + i, prompt=_prompt(i, 4, cfg.vocab_size),
+                    max_new_tokens=3) for i in range(2)]
+    eng.run(list(reqs))                          # compile outside the trace
+    eng.reset()
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("caller"):
+            eng.run(list(reqs))
+    evs = _host_spans(str(tmp_path))
+    named = collections.defaultdict(list)
+    for e in evs:
+        named[e[0].split("#")[0]].append(e)
+    caller, = named["caller"]
+    steps = named["engine.step"]
+    assert len(steps) == eng.n_decode_ticks + 1   # the last finds no work
+    assert all(_inside(s, caller) for s in steps)
+
+    def parent(e, among):
+        return [p for p in among if _inside(e, p)]
+
+    admits = named["engine.admit"]
+    assert sorted(int(a[3]["rid"]) for a in admits) == [10, 11]
+    for kind, children in (("engine.admit", ("engine.page_plan",
+                                             "engine.prefill",
+                                             "engine.admit_wait")),
+                           ("engine.decode", ("engine.decode_dispatch",
+                                              "engine.decode_wait",
+                                              "engine.emit"))):
+        outer = named[kind]
+        assert outer and all(len(parent(o, steps)) == 1 for o in outer)
+        for child in children:
+            assert len(named[child]) == len(outer), child
+            assert all(len(parent(c, outer)) == 1 for c in named[child])
+    assert len(named["engine.decode"]) == eng.n_decode_ticks
+    # one upload per admission and one per finish
+    assert len(named["engine.page_table_upload"]) == 4
+
+
+def test_h2d_uploads_count_every_host_array():
+    """Two arrays a decode tick, five an admission without a prefix hit
+    (page table, prompt, logit index, slot, prefix length) and one page
+    table a finish; reset() zeroes the count."""
+    cfg, params = _cfg_params()
+    eng = ContinuousBatchingEngine(
+        cfg, params, EngineConfig(n_slots=2, max_ctx=16,
+                                  backend="reference"), time_fn=_FROZEN)
+    assert eng.h2d_uploads == 0
+    reqs = [Request(rid=i, prompt=_prompt(i, 4 + i, cfg.vocab_size),
+                    max_new_tokens=2 + i, arrival=0.0) for i in range(3)]
+    _, m = eng.run(list(reqs))
+    assert m["prefix_hit_rate"] == 0.0
+    assert m["h2d_uploads"] == eng.h2d_uploads == (
+        2 * m["n_decode_ticks"] + 5 * m["n_prefills"] + 1 * len(reqs))
+    eng.reset()
+    assert eng.h2d_uploads == 0 and eng.metrics()["h2d_uploads"] == 0
+
+
+def _scopes_in(lowered) -> set:
+    """The named scopes in the location names of a lowered function (a
+    scan body's own locations start below the scan)."""
+    import re
+    names = re.findall(r'loc\("([^"]*)"', lowered.as_text(debug_info=True))
+    # a transformed scope reads e.g. transpose(jvp(attn))
+    return {w for n in names for w in re.findall(r"\w+", n)} & set(_SCOPES)
+
+
+_SCOPES = ("embed", "layers", "head", "attn", "mlp", "kv_write",
+           "paged_gqa", "salr_base", "salr_base_decode", "salr_adapter")
+
+
+def test_model_steps_carry_their_named_scopes():
+    """The decode and prefill steps and the train step are traced under
+    the named scopes that the trace reduction charges device time to."""
+    from repro.optim.adamw import AdamW
+    from repro.train.state import train_state_from_params
+    from repro.train.step import make_train_step
+    cfg, params = _cfg_params()
+    eng = ContinuousBatchingEngine(cfg, params,
+                                   EngineConfig(n_slots=2, max_ctx=16))
+    tokens = jnp.zeros((2, 1), jnp.int32)
+    pos = jnp.zeros((2,), jnp.int32)
+    assert _scopes_in(eng._decode.lower(eng.params, eng.cache, tokens,
+                                        pos)) == {
+        "embed", "layers", "head", "attn", "mlp", "kv_write", "paged_gqa"}
+    prompt = jnp.zeros((1, 8), jnp.int32)
+    assert {"embed", "layers", "head", "attn", "mlp"} <= _scopes_in(
+        eng._prefill.lower(eng.params, prompt, jnp.int32(3), None, None, 0))
+    opt = AdamW(lr=1e-3)
+    state = train_state_from_params(params, opt)
+    step = jax.jit(make_train_step(cfg, opt))
+    batch = {"tokens": jnp.zeros((2, 8), jnp.int32),
+             "labels": jnp.zeros((2, 8), jnp.int32)}
+    assert {"embed", "layers", "head", "attn", "mlp", "salr_base",
+            "salr_base_decode", "salr_adapter"} <= _scopes_in(
+        step.lower(state, batch))
