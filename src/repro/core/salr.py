@@ -31,6 +31,7 @@ paths down to the ``backend`` argument here.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from functools import partial
 from typing import Optional
@@ -143,12 +144,46 @@ def _is_tiled(base) -> bool:
     return isinstance(base, (bm.TiledBitmapWeight, bm.QTiledBitmapWeight))
 
 
+_KERNEL_DECODE: list = []           # stack of active kernel_decode scopes
+
+
+@contextlib.contextmanager
+def kernel_decode():
+    """Trace-time scope of the train step (``train/step.py``): inside it
+    a reference-route decode of a tiled bitmap base may run the Pallas
+    decode kernel (see ``_decode_in_kernel``).  Outside it the reference
+    route keeps the jnp ``tile_decode``, so the oracles that compare
+    against the kernels share no decode code with them."""
+    _KERNEL_DECODE.append(True)
+    try:
+        yield
+    finally:
+        _KERNEL_DECODE.pop()
+
+
+def _decode_in_kernel(base) -> bool:
+    """Whether a base decodes through the Pallas decode kernel: a tiled
+    bitmap inside ``kernel_decode``, on a single TPU chip, where the jnp
+    decode's lane gather is the slow part of a train step.  Elsewhere
+    the jnp decode stays: on other backends the kernel would run
+    interpreted, and across chips a base may be sharded, which a Pallas
+    call outside ``shard_map`` cannot be."""
+    return (bool(_KERNEL_DECODE)
+            and isinstance(base, bm.TiledBitmapWeight)
+            and jax.default_backend() == "tpu"
+            and jax.device_count() == 1)
+
+
 def materialize_base(base) -> jax.Array:
     """Dense W_hat from any base representation (reference decode path).
 
     Tiled bases decode in the logical orientation with zero-padded
     columns up to the tile multiple; callers slice to ``layer.d_out``.
     """
+    if _decode_in_kernel(base):
+        from repro.kernels import ops  # deferred: kernels import core.bitmap
+        # the base is frozen: no tangent may reach the kernel
+        return ops.bitmap_decode_t(jax.lax.stop_gradient(base)).T
     if isinstance(base, bm.BitmapWeight):
         return bm.decode(base)
     if isinstance(base, bm.NMWeight):

@@ -14,7 +14,8 @@ Kernels (see DESIGN.md §3 for the GPU->TPU adaptation rationale):
   grouped_spmm -- ragged grouped GEMM over expert-stacked bases (MoE
                   k-way dispatch; tile->expert map via scalar prefetch)
   bitmap_decode -- the in-kernel tiled-bitmap decode every bitmap-family
-                  GEMM shares (shifts, MXU prefix popcount, select)
+                  GEMM shares (shifts, MXU prefix popcount, select), and
+                  the same decode alone writing W^T (the train route)
 
 See docs/kernels.md for the kernel-authoring guide (wrapper decorator
 contract, tiled layout, custom-VJP convention, grouped grid design).

@@ -19,6 +19,11 @@ lowers (no cumsum, no lane gather, no lane-merging reshape):
 The result is W^T for the block, contracted against x with
 :func:`dot_xwt`.  Decoded values are exact copies of the stored ones,
 so the tile equals ``core.bitmap.tile_decode`` bit for bit.
+
+:func:`bitmap_decode_pallas` runs the same decode alone, writing the
+whole W^T once: the train step's reference route contracts it with
+XLA's own GEMM, where the GEMM kernels would redecode each weight block
+once per M block.
 """
 from __future__ import annotations
 
@@ -76,6 +81,35 @@ def decode_bitmap_t(words, segments, dtype):
     for ref, first in segments:
         acc = _select_rows(acc, slots, ref, first)
     return acc.astype(dtype)
+
+
+def _decode_kernel(words_ref, values_ref, o_ref):
+    o_ref[...] = decode_bitmap_t(words_ref[...], ((values_ref, 0),),
+                                 o_ref.dtype)
+
+
+def bitmap_decode_pallas(words: jax.Array, values: jax.Array, *,
+                         block_k: int, interpret: bool) -> jax.Array:
+    """W^T ``(n_tiles * tile, K)`` in the values' dtype, each weight
+    decoded once, from tile-major ``words (n_tiles, tile//32, K)`` and
+    ``values (n_tiles, cap_t, K)``."""
+    n_tiles, wpt, kdim = words.shape
+    tile = wpt * 32
+    cap_t = values.shape[1]
+    assert values.shape == (n_tiles, cap_t, kdim) and kdim % block_k == 0
+    return pl.pallas_call(
+        _decode_kernel,
+        grid=(n_tiles, kdim // block_k),
+        in_specs=[
+            pl.BlockSpec((None, wpt, block_k), lambda ni, ki: (ni, 0, ki)),
+            pl.BlockSpec((None, cap_t, block_k), lambda ni, ki: (ni, 0, ki)),
+        ],
+        out_specs=pl.BlockSpec((tile, block_k), lambda ni, ki: (ni, ki)),
+        out_shape=jax.ShapeDtypeStruct((n_tiles * tile, kdim), values.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+    )(words, values)
 
 
 def nf4_halves_scratch(half: int, block_k: int) -> tuple:

@@ -13,6 +13,7 @@ scope so dispatch never pays a per-trace import.
 """
 from __future__ import annotations
 
+import functools
 import inspect
 import math
 
@@ -21,6 +22,7 @@ import jax.numpy as jnp
 
 from repro.core import bitmap as bm
 from repro.core.quant import quantize_nf4
+from repro.kernels.bitmap_decode import bitmap_decode_pallas
 from repro.kernels.bitmap_spmm import bitmap_spmm_pallas
 from repro.kernels.contract import kernel_contract
 from repro.kernels.fused_lora import fused_lora_pallas
@@ -135,6 +137,18 @@ def bitmap_matmul(x: jax.Array, tbw: bm.TiledBitmapWeight, *,
     return bitmap_spmm_pallas(x, tbw.words, tbw.values, cols=tbw.cols,
                               cap_t=tbw.cap_t, block_m=block_m, block_k=bk,
                               interpret=interpret)
+
+
+@kernel_contract(kind="linear", differentiable=False)
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def bitmap_decode_t(tbw: bm.TiledBitmapWeight, *,
+                    interpret=None) -> jax.Array:
+    """W_hat^T ``(cols, rows)`` of a tiled bitmap by the in-kernel decode,
+    each weight decoded once: ``bm.tile_decode(tbw).T`` bit for bit.  No
+    autodiff rule: callers hand it a frozen base."""
+    bk = _divisor_block(tbw.rows, LANES)
+    return bitmap_decode_pallas(tbw.words, tbw.values, block_k=bk,
+                                interpret=resolve_interpret(interpret))
 
 
 @_batched_matmul("block_n", "block_k", "interpret",

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.core import execplan
 from repro.core.pytree import combine
+from repro.core.salr import kernel_decode
 from repro.models import model as M
 from repro.optim.adamw import AdamW, residual_lr_scale_tree
 from repro.train.state import TrainState
@@ -52,6 +53,13 @@ def make_train_step(cfg: ArchConfig, opt: AdamW, *, microbatches: int = 1,
     loss_fn = make_loss_fn(cfg, loss_chunk=loss_chunk)
 
     def train_step(state: TrainState, batch):
+        # the frozen tiled bases decode in the Pallas kernel on one TPU
+        # chip (core/salr.kernel_decode); custom-VJP backwards trace
+        # after the loss returns, so the scope holds the whole step
+        with kernel_decode():
+            return _step(state, batch)
+
+    def _step(state: TrainState, batch):
         if microbatches > 1:
             def split(x):
                 b = x.shape[0]

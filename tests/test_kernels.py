@@ -140,6 +140,44 @@ def test_bitmap_matmul_batched_input():
                                rtol=2e-4, atol=2e-4)
 
 
+# --------------------------------------- standalone decode (train route)
+
+def _tiled_at(kdim, n, dtype, full_rows=0, seed=0):
+    """A tiled bitmap of a (kdim, n) base at p = 0.5, its columns padded
+    to the tile multiple; the first tile of the first ``full_rows`` rows
+    is set to overflow, so those cells hold exactly ``cap_t`` values."""
+    tile = bm.default_tile(n)
+    cols = bm.round_up(n, tile)
+    cap_t = bm.tiled_capacity(tile, 0.5)
+    k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
+    w = jax.random.normal(k1, (kdim, cols)).astype(dtype)
+    mask = jax.random.bernoulli(k2, 0.5, (kdim, cols)) & (jnp.arange(cols) < n)
+    mask = mask.at[:full_rows, :tile].set(True)
+    tbw, _ = bm.tile_encode(jnp.where(mask, w, 0), mask, tile, cap_t)
+    return tbw
+
+
+@pytest.mark.parametrize("kk,nn,dtype,full_rows", [
+    (576, 576, jnp.bfloat16, 0),      # N padded to 768
+    (576, 192, jnp.bfloat16, 0),
+    (576, 1536, jnp.bfloat16, 0),
+    (1536, 576, jnp.bfloat16, 0),     # block_k 128
+    (576, 576, jnp.bfloat16, 8),      # full cells: the last value row
+    (64, 64, jnp.float32, 4),
+], ids=["576x576", "576x192", "576x1536", "1536x576", "576x576-full",
+        "64x64-f32-full"])
+def test_bitmap_decode_t_bit_exact(kk, nn, dtype, full_rows):
+    tbw = _tiled_at(kk, nn, dtype, full_rows)
+    if full_rows:
+        per_cell = jnp.sum(jax.lax.population_count(tbw.words), axis=1)
+        assert int(jnp.max(per_cell)) == tbw.cap_t
+    wt = ops.bitmap_decode_t(tbw, interpret=True)
+    want = bm.tile_decode(tbw).T
+    assert wt.shape == want.shape == (tbw.cols, kk) and wt.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(wt).view(np.uint8),
+                                  np.asarray(want).view(np.uint8))
+
+
 # ------------------------------------------- paged decode vs dense decode
 
 def _paged_pool(rows, pos, ps, n_row_pages, fill):

@@ -11,7 +11,8 @@ from repro.core import prune, residual
 from repro.core.pytree import combine, split_trainable
 from repro.core.quant import dequantize_nf4, quantize_nf4
 from repro.core.salr import (SALRConfig, apply_salr, compress_linear,
-                             effective_weight, layer_nbytes)
+                             effective_weight, force_backend, kernel_decode,
+                             layer_nbytes)
 
 
 # ------------------------------------------------------------------ prune
@@ -232,3 +233,88 @@ def test_salr_grad_only_flows_to_adapters():
     gl = jax.tree_util.tree_leaves(g)
     assert len(gl) == 4
     assert any(float(jnp.abs(x).sum()) > 0 for x in gl)
+
+
+@pytest.mark.parametrize("backend", ["reference", "kernel"])
+def test_kernel_decode_grads_equal_jnp_decode(monkeypatch, backend):
+    """Adapter gradients through a two-layer remat'd scan of tiled SALR
+    linears are the same whether the frozen base decodes by the Pallas
+    decode kernel (interpreted here, as it runs on a TPU) or by the jnp
+    decode: no tangent reaches the kernel, and remat traces it.  The
+    kernel backend's backward replays the reference route over the whole
+    layer, base included."""
+    from repro.core import bitmap as bm
+    from repro.core import salr
+    d = 64
+    cfg = SALRConfig(sparsity=0.5, method="bitmap", lora_rank=4, res_rank=4,
+                     cap_align=8)
+    keys = jax.random.split(jax.random.PRNGKey(21), 4)
+    layers = [compress_linear(k, jax.random.normal(k, (d, d)) / 8.0, cfg,
+                              cap_t=bm.tiled_capacity(d, 0.5))
+              for k in keys[:2]]
+    assert isinstance(layers[0].base, bm.TiledBitmapWeight)
+    stack = jax.tree.map(lambda *a: jnp.stack(a), *layers)
+    trainable, frozen = split_trainable(stack)
+    x = jax.random.normal(keys[2], (8, d))
+
+    def loss(trainable, frozen, x):
+        def body(h, layer):
+            return jnp.tanh(apply_salr(h, layer, backend=backend)), None
+        h, _ = jax.lax.scan(jax.checkpoint(body, prevent_cse=False), x,
+                            combine(trainable, frozen))
+        return jnp.mean(jnp.square(h))
+
+    def run():
+        fn = jax.value_and_grad(loss)
+        jaxpr = str(jax.make_jaxpr(fn)(trainable, frozen, x))
+        return jax.jit(fn)(trainable, frozen, x), "bitmap_decode_t" in jaxpr
+
+    want, decoded_in_kernel = run()
+    assert not decoded_in_kernel
+    monkeypatch.setattr(
+        salr, "_decode_in_kernel",
+        lambda base: isinstance(base, bm.TiledBitmapWeight))
+    got, decoded_in_kernel = run()
+    assert decoded_in_kernel
+    leaves = jax.tree.leaves(want)
+    assert any(float(jnp.max(jnp.abs(g))) > 0 for g in leaves[1:])
+    for a, b in zip(jax.tree.leaves(got), leaves):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_kernel_decode_is_the_train_steps_choice(monkeypatch):
+    """On one TPU chip the train step decodes its tiled bases in the
+    Pallas kernel; a reference-route forward outside the train step (the
+    oracles the kernels are checked against) keeps the jnp decode.
+    Traced only: what the code observes on a TPU, from this CPU host."""
+    from repro import configs
+    from repro.models import model as M
+    from repro.optim.adamw import AdamW
+    from repro.train.state import make_train_state
+    from repro.train.step import make_train_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda *a: 1)
+    cfg = configs.get("smollm_135m", smoke=True)
+    opt = AdamW(lr=1e-3)
+    state = jax.eval_shape(
+        lambda: make_train_state(jax.random.PRNGKey(0), cfg, opt))
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    batch = {"tokens": tokens, "labels": tokens}
+    step = str(jax.make_jaxpr(make_train_step(cfg, opt))(state, batch))
+    assert "bitmap_decode_t" in step
+    params = combine(state.trainable, state.frozen)
+    with force_backend("reference"):
+        oracle = str(jax.make_jaxpr(
+            lambda p: M.forward_hidden(p, cfg, tokens))(params))
+    assert "cumsum" in oracle                 # the jnp tile_decode
+    assert "bitmap_decode_t" not in oracle
+    # across chips a base may be sharded: the jnp decode stays
+    monkeypatch.setattr(jax, "device_count", lambda *a: 4)
+    layer = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape[1:],
+                                                        a.dtype),
+                         params["groups"][0][0]["mlp"]["down"])
+    with kernel_decode():
+        one = str(jax.make_jaxpr(lambda x, lin: apply_salr(
+            x, lin, backend="reference"))(jnp.ones((2, layer.d_in)), layer))
+    assert "cumsum" in one and "bitmap_decode_t" not in one
